@@ -17,9 +17,11 @@ from repro.apps import segmentation
 from repro.configs.festivus_imagery import SMOKE as IMG_CFG
 from repro.core import ChunkStore, Festivus, InMemoryObjectStore
 from repro.data import imagery
+from repro.kernels.backend import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     store = InMemoryObjectStore()
     cs = ChunkStore(Festivus(store), "bucket")
     spec = imagery.SceneSpec(tile_px=96, temporal_depth=10, num_fields=12,

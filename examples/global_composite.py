@@ -16,9 +16,11 @@ from repro.configs.festivus_imagery import SMOKE as IMG_CFG
 from repro.core import ChunkStore, Festivus, FlakyObjectStore, InMemoryObjectStore
 from repro.core.tiling import UTMGridSpec, zone_tiles
 from repro.data import imagery
+from repro.kernels.backend import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     inner = InMemoryObjectStore()
     flaky = FlakyObjectStore(inner, failure_rate=0.02, seed=7)
     cs = ChunkStore(Festivus(flaky), "bucket")

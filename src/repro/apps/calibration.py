@@ -177,9 +177,6 @@ def run_campaign(cs_in: ChunkStore, cs_out: ChunkStore, scene_keys,
 
     engine = ClusterEngine(cs_in.fs.store, meta=cs_in.fs.meta, config=config)
     report = engine.run({k: k for k in scene_keys}, handler)
-    if not report.all_done:
-        raise RuntimeError(
-            f"campaign incomplete: {report.queue_stats} "
-            f"dead={report.dead_tasks}")
+    report.raise_if_incomplete("calibration")
     return {"scenes": len(scene_keys), "stats": report.queue_stats,
             "results": report.results, "report": report}

@@ -85,9 +85,6 @@ def run_composite_campaign(cs: ChunkStore, tile_names: Sequence[str],
 
     engine = ClusterEngine(cs.fs.store, meta=cs.fs.meta, config=config)
     report = engine.run({t: t for t in tile_names}, handler)
-    if not report.all_done:
-        raise RuntimeError(
-            f"composite campaign incomplete: {report.queue_stats} "
-            f"dead={report.dead_tasks}")
+    report.raise_if_incomplete("composite")
     return {"tiles": len(tile_names), "stats": report.queue_stats,
             "report": report}

@@ -173,9 +173,6 @@ def run_segmentation_campaign(cs, tile_names, cfg: ImageryConfig,
 
     engine = ClusterEngine(cs.fs.store, meta=cs.fs.meta, config=config)
     report = engine.run({t: t for t in tile_names}, handler)
-    if not report.all_done:
-        raise RuntimeError(
-            f"segmentation campaign incomplete: {report.queue_stats} "
-            f"dead={report.dead_tasks}")
+    report.raise_if_incomplete("segmentation")
     return {"tiles": len(tile_names), "stats": report.queue_stats,
             "report": report}

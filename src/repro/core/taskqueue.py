@@ -59,6 +59,8 @@ class Task:
     started_at: float = 0.0
     completed_at: float = 0.0
     result: Any = None
+    #: the first failure's text: a retry that fails differently (after an
+    #: out-of-memory, say) does not hide the cause
     error: Optional[str] = None
     #: how many workers hold (possibly speculative) claims right now
     active_claims: int = 0
@@ -285,7 +287,8 @@ class TaskQueue:
             task.active_claims = len(task.claimants)
             if task.active_claims > 0:
                 return  # a speculative twin is still running
-            task.error = error
+            if task.error is None:
+                task.error = error
             if task.attempt > task.max_retries:
                 self._transition(RUNNING, DEAD)
                 task.state = DEAD
@@ -315,7 +318,7 @@ class TaskQueue:
             if task.attempt > task.max_retries:
                 self._transition(RUNNING, DEAD)
                 task.state = DEAD
-                task.error = "lease expired (max retries)"
+                task.error = task.error or "lease expired (max retries)"
                 self.stats["dead"] += 1
             else:
                 self._transition(RUNNING, PENDING)

@@ -9,10 +9,14 @@ VMEM accumulators instead:
 
 * Grid ``(H/block_h, T)`` — T is the trailing (sequential) axis, so the
   weighted-sum and weight-sum accumulators live in VMEM scratch across the
-  whole time stack; each input image tile is read from HBM exactly once and
-  no [T, H, W, C]-sized intermediate ever exists.
-* Block = a (block_h, W, C) image strip: contiguous in memory, lane-aligned
-  in W, C; block_h chosen by the wrapper to fit comfortably in VMEM.
+  whole time stack; the kernel reads each input strip from HBM exactly once.
+* Block = a (C, block_h, W) bands-major image strip: W sits on the 128
+  lanes and block_h on the sublanes.  With the 4 bands minor, as the public
+  [T, H, W, C] layout has them, Mosaic pads the band axis to 128 lanes both
+  in VMEM and in the HBM operand (32x the data for 4 bands), and a
+  paper-width (4096 or 6144) tile compiles for neither.  The wrapper
+  transposes to [T, C, H, W] and back, inside the caller's jit; that
+  transpose is one stack-sized copy in HBM.
 * Accumulation in f32 regardless of input dtype (bf16-safe over long
   stacks: Landsat revisits give T of O(100)).
 """
@@ -26,7 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.backend import resolve_interpret
+from repro.kernels.backend import resolve_interpret, row_block
 
 
 def _composite_kernel(img_ref, w_ref, o_ref, num_scratch, den_scratch, *,
@@ -39,14 +43,14 @@ def _composite_kernel(img_ref, w_ref, o_ref, num_scratch, den_scratch, *,
         num_scratch[...] = jnp.zeros_like(num_scratch)
         den_scratch[...] = jnp.zeros_like(den_scratch)
 
-    img = img_ref[0].astype(jnp.float32)      # [bh, W, C]
+    img = img_ref[0].astype(jnp.float32)      # [C, bh, W]
     w = w_ref[0].astype(jnp.float32)          # [bh, W]
-    num_scratch[...] += img * w[..., None]
+    num_scratch[...] += img * w[None]
     den_scratch[...] += w
 
     @pl.when(t == nt - 1)
     def _finish():
-        den = den_scratch[...][..., None] + eps
+        den = den_scratch[...][None] + eps
         o_ref[...] = (num_scratch[...] / den).astype(o_ref.dtype)
 
 
@@ -62,22 +66,21 @@ def composite_fwd(images: jax.Array, weights: jax.Array, *,
     T, H, W, C = images.shape
     if weights.shape != (T, H, W):
         raise ValueError(f"weights {weights.shape} != {(T, H, W)}")
-    block_h = min(block_h, H)
-    if H % block_h:
-        raise ValueError(f"H={H} not divisible by block_h={block_h}")
+    block_h = row_block(H, block_h, images.dtype, weights.dtype)
     grid = (H // block_h, T)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_composite_kernel, eps=eps),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_h, W, C), lambda i, t: (t, i, 0, 0)),
+            pl.BlockSpec((1, C, block_h, W), lambda i, t: (t, 0, i, 0)),
             pl.BlockSpec((1, block_h, W), lambda i, t: (t, i, 0)),
         ],
-        out_specs=pl.BlockSpec((block_h, W, C), lambda i, t: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((H, W, C), images.dtype),
+        out_specs=pl.BlockSpec((C, block_h, W), lambda i, t: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((C, H, W), images.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_h, W, C), jnp.float32),
+            pltpu.VMEM((C, block_h, W), jnp.float32),
             pltpu.VMEM((block_h, W), jnp.float32),
         ],
         interpret=interpret,
-    )(images, weights)
+    )(jnp.transpose(images, (0, 3, 1, 2)), weights)
+    return jnp.transpose(out, (1, 2, 0))

@@ -15,6 +15,11 @@ sequential-T grid pattern as the composite kernel.  The shifted views cost
 one extra HBM read per input; on TPU they would be produced by the XLA
 fusion feeding the kernel.  Boundary semantics match the oracle: shifted
 validity is zero outside the frame, so edge pixels contribute no gradient.
+
+Layout: the kernel streams bands-major ``(C, block_h, W)`` strips, so W sits
+on the 128 lanes (see :mod:`repro.kernels.composite` for why the public
+band-minor layout cannot compile at paper widths).  The wrapper transposes
+``[T, H, W, C]`` to ``[T, C, H, W]`` before shifting, inside the caller's jit.
 """
 
 from __future__ import annotations
@@ -26,7 +31,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.backend import resolve_interpret
+from repro.kernels.backend import resolve_interpret, row_block
 
 
 def _grad_kernel(x_ref, xe_ref, xs_ref, v_ref, ve_ref, vs_ref,
@@ -39,18 +44,18 @@ def _grad_kernel(x_ref, xe_ref, xs_ref, v_ref, ve_ref, vs_ref,
         gs[...] = jnp.zeros_like(gs)
         cs[...] = jnp.zeros_like(cs)
 
-    x = x_ref[0].astype(jnp.float32)    # [bh, W, C]
+    x = x_ref[0].astype(jnp.float32)    # [C, bh, W]
     xe = xe_ref[0].astype(jnp.float32)  # east-shifted
     xs = xs_ref[0].astype(jnp.float32)  # south-shifted
     v = v_ref[0].astype(jnp.float32)    # [bh, W]
     ve = ve_ref[0].astype(jnp.float32)
     vs = vs_ref[0].astype(jnp.float32)
 
-    vx = (v * ve)[..., None]
-    vy = (v * vs)[..., None]
+    vx = (v * ve)[None]
+    vy = (v * vs)[None]
     dx = (xe - x) * vx
     dy = (xs - x) * vy
-    mag = jnp.sqrt(jnp.sum(dx * dx, axis=-1) + jnp.sum(dy * dy, axis=-1) + eps)
+    mag = jnp.sqrt(jnp.sum(dx * dx, axis=0) + jnp.sum(dy * dy, axis=0) + eps)
     gs[...] += mag * v
     cs[...] += v
 
@@ -72,23 +77,20 @@ def grad_mag_fwd(images: jax.Array, valid: jax.Array, *, block_h: int = 8,
     T, H, W, C = images.shape
     if valid.shape != (T, H, W):
         raise ValueError(f"valid {valid.shape} != {(T, H, W)}")
-    block_h = min(block_h, H)
-    if H % block_h:
-        raise ValueError(f"H={H} not divisible by block_h={block_h}")
+    block_h = row_block(H, block_h, images.dtype)
 
-    imf = images
+    imf = jnp.transpose(images, (0, 3, 1, 2))  # [T, C, H, W]
     vf = valid.astype(images.dtype)
     # east neighbour (shift left along W); out-of-frame -> invalid
-    xe = jnp.concatenate([imf[:, :, 1:, :], jnp.zeros_like(imf[:, :, :1, :])],
-                         axis=2)
+    xe = jnp.concatenate([imf[..., 1:], jnp.zeros_like(imf[..., :1])], axis=3)
     ve = jnp.concatenate([vf[:, :, 1:], jnp.zeros_like(vf[:, :, :1])], axis=2)
     # south neighbour (shift up along H)
-    xs = jnp.concatenate([imf[:, 1:, :, :], jnp.zeros_like(imf[:, :1, :, :])],
-                         axis=1)
+    xs = jnp.concatenate([imf[:, :, 1:, :], jnp.zeros_like(imf[:, :, :1, :])],
+                         axis=2)
     vs = jnp.concatenate([vf[:, 1:, :], jnp.zeros_like(vf[:, :1, :])], axis=1)
 
     grid = (H // block_h, T)
-    img_spec = pl.BlockSpec((1, block_h, W, C), lambda i, t: (t, i, 0, 0))
+    img_spec = pl.BlockSpec((1, C, block_h, W), lambda i, t: (t, 0, i, 0))
     msk_spec = pl.BlockSpec((1, block_h, W), lambda i, t: (t, i, 0))
     out_spec = pl.BlockSpec((block_h, W), lambda i, t: (i, 0))
     return pl.pallas_call(

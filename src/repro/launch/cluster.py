@@ -716,9 +716,24 @@ class ClusterReport:
     #: no chaos layer was registered.
     chaos: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
+    #: dead task id -> the text of its first failure
+    errors: Dict[str, str] = dataclasses.field(default_factory=dict)
+
     @property
     def all_done(self) -> bool:
         return not self.dead_tasks and self.queue_stats["completed"] == self.tasks
+
+    def raise_if_incomplete(self, campaign: str) -> None:
+        """Raise unless every task completed.  The message carries the first
+        dead task's error, so a device out-of-memory or compile error in a
+        handler reads at the end of the output, not only a count."""
+        if self.all_done:
+            return
+        first = next(iter(self.errors.items()), None)
+        raise RuntimeError(
+            f"{campaign} campaign incomplete: {self.queue_stats} "
+            f"dead={self.dead_tasks}"
+            + (f"; {first[0]} failed with: {first[1]}" if first else ""))
 
     @property
     def read_bandwidth_bytes_per_s(self) -> float:
@@ -1615,13 +1630,15 @@ class ClusterEngine:
         ]
         store_stats = StoreStats.merge(r.store_stats for r in per_worker)
         festivus_stats = FestivusStats.merge(r.festivus_stats for r in per_worker)
+        dead = queue.dead_tasks()
         return ClusterReport(
             nodes=self.config.nodes, tasks=ntasks, makespan_s=makespan,
             bytes_read=store_stats.bytes_read,
             bytes_written=store_stats.bytes_written,
             store_stats=store_stats, festivus_stats=festivus_stats,
             queue_stats=dict(queue.stats),
-            dead_tasks=[t.task_id for t in queue.dead_tasks()],
+            dead_tasks=[t.task_id for t in dead],
+            errors={t.task_id: t.error for t in dead if t.error},
             results=queue.results(), per_worker=per_worker,
             meta_ops=sum(r.meta_ops for r in per_worker),
             joined=self._joined, left=self._left,

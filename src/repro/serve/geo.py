@@ -402,10 +402,7 @@ class GeoTileFleet:
         engine = ClusterEngine(self.store, meta=self.meta,
                                config=self._config(controller))
         report = engine.run(tasks, handler, arrivals=arrivals, pools=pools)
-        if not report.all_done:
-            raise RuntimeError(f"geo serving campaign incomplete: "
-                               f"{report.queue_stats} "
-                               f"dead={report.dead_tasks}")
+        report.raise_if_incomplete("geo serving")
 
         # -- gather ------------------------------------------------------------
         samples: List[Tuple[float, float, str]] = []

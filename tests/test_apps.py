@@ -108,6 +108,30 @@ def test_campaign_rejects_split_stores():
 # ---------------------------------------------------------------------------
 # composite (§V.C)
 # ---------------------------------------------------------------------------
+def test_scene_stack_matches_per_timestep_scenes():
+    """The stack builds the field map once and generates timesteps on
+    threads; it must equal scene() run one timestep at a time, bit for bit."""
+    spec = imagery.SceneSpec(tile_px=40, temporal_depth=5, seed=3)
+    imgs, valid = imagery.scene_stack(spec)
+    for t in range(spec.temporal_depth):
+        img_t, valid_t = imagery.scene(spec, t)
+        assert imgs[t].tobytes() == img_t.tobytes(), t
+        assert (valid[t] == valid_t).all(), t
+
+
+@pytest.mark.parametrize("campaign", [composite.run_composite_campaign,
+                                      segmentation.run_segmentation_campaign])
+def test_campaign_failure_carries_the_handler_error(chunkstore, campaign):
+    """A failing tile raises with its handler's own error text, not only a
+    count, so a device error reads at the end of a run's output."""
+    from repro.launch.cluster import ClusterConfig
+
+    with pytest.raises(RuntimeError,
+                       match="tiles/missing failed with: FileNotFoundError"):
+        campaign(chunkstore, ["tiles/missing"], IMG_CFG,
+                 engine_config=ClusterConfig(nodes=1, max_retries=0))
+
+
 def test_composite_prefers_cloud_free(scene_store):
     cs, spec = scene_store
     imgs, valid = imagery.read_scene_stack(cs, "tiles/t0")

@@ -67,6 +67,17 @@ def test_max_retries_dead_letter():
     assert q.dead_tasks()[0].error == "boom"
 
 
+def test_dead_letter_keeps_the_first_error():
+    """A retry that fails differently does not hide the first cause."""
+    q = TaskQueue()
+    q.submit("t", 0, max_retries=1)
+    for worker, error in (("w0", "out of memory"), ("w1", "boom")):
+        q.claim(worker)
+        q.fail("t", worker, error)
+    assert q.counts()[DEAD] == 1
+    assert q.dead_tasks()[0].error == "out of memory"
+
+
 def test_idempotent_completion():
     q = TaskQueue()
     q.submit("t", 0)
