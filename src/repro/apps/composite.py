@@ -17,11 +17,11 @@ from __future__ import annotations
 
 from typing import Dict, Optional, Sequence
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.festivus_imagery import ImageryConfig
 from repro.core.chunkstore import ChunkStore
+from repro.core.spans import span, to_device, to_host
 from repro.data import imagery
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
@@ -37,22 +37,28 @@ def cloud_score(images: np.ndarray, cfg: ImageryConfig) -> np.ndarray:
     """Simple reflectance cloud mask ([12] Oreopoulos et al. in the paper):
     clouds are bright and spectrally flat.  images [T, H, W, C] -> [T, H, W]
     score in [0, 1]."""
-    brightness = images[..., :3].mean(axis=-1)
-    flatness = 1.0 - np.abs(images[..., 0] - images[..., 2])
-    score = np.clip(
-        (brightness - cfg.cloud_reflectance_threshold) * 4.0, 0.0, 1.0)
-    return score * np.clip(flatness, 0.0, 1.0)
+    with span("band_math"):
+        brightness = images[..., :3].mean(axis=-1)
+        flatness = 1.0 - np.abs(images[..., 0] - images[..., 2])
+        score = np.clip(
+            (brightness - cfg.cloud_reflectance_threshold) * 4.0, 0.0, 1.0)
+        return score * np.clip(flatness, 0.0, 1.0)
 
 
 def composite_tile(images: np.ndarray, cfg: ImageryConfig,
                    impl: str = "auto") -> np.ndarray:
     """One tile: [T, H, W, C] stack -> [H, W, C] cloud-free composite."""
     score = cloud_score(images, cfg)
-    weights = kref.composite_weights(
-        jnp.asarray(images), jnp.asarray(score),
-        nir=jnp.asarray(images[..., 1]), red=jnp.asarray(images[..., 0]))
-    out = kops.composite(jnp.asarray(images), weights, impl=impl)
-    return np.asarray(out)
+    unread, score_d, nir, red = to_device(images, score, images[..., 1],
+                                          images[..., 0])
+    with span("dispatch"):
+        weights = kref.composite_weights(unread, score_d, nir=nir, red=red)
+    del unread, score_d, nir, red  # the device frees them after the call
+    (stack,) = to_device(images)
+    with span("dispatch"):
+        out = kops.composite(stack, weights, impl=impl)
+    del stack
+    return to_host(out)
 
 
 def run_composite_campaign(cs: ChunkStore, tile_names: Sequence[str],
@@ -79,7 +85,8 @@ def run_composite_campaign(cs: ChunkStore, tile_names: Sequence[str],
                          (min(cfg.chunk_px, comp.shape[0]),
                           min(cfg.chunk_px, comp.shape[1]), comp.shape[2]),
                          codec="zlib", pyramid_levels=2)
-        arr.write_region((0, 0, 0), comp)
+        with span("write"):
+            arr.write_region((0, 0, 0), comp)
         arr.build_pyramid()  # the JPX multi-resolution serving layer
         return {"tile": tile_name, "mean": float(comp.mean())}
 

@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.configs.festivus_imagery import ImageryConfig
 from repro.apps.composite import cloud_score
+from repro.core.spans import span, to_device, to_host
 from repro.kernels import ops as kops
 
 
@@ -37,10 +38,17 @@ def temporal_edges(images: np.ndarray, valid: np.ndarray,
                    cfg: ImageryConfig, impl: str = "auto") -> np.ndarray:
     """Stages 1-3: temporal-mean gradient -> binary edge map [H, W] bool."""
     score = cloud_score(images, cfg)
-    valid_eff = jnp.asarray(valid) & (jnp.asarray(score) < 0.5)
-    gsum, count = kops.grad_mag(jnp.asarray(images), valid_eff, impl=impl)
-    mean_grad = gsum / jnp.maximum(count, 1.0)
-    return np.asarray(mean_grad > cfg.edge_threshold)
+    valid_d, score_d = to_device(valid, score)
+    with span("dispatch"):
+        valid_eff = valid_d & (score_d < 0.5)
+    del valid_d, score_d  # the device frees them after the ops
+    (stack,) = to_device(images)
+    with span("dispatch"):
+        gsum, count = kops.grad_mag(stack, valid_eff, impl=impl)
+        del stack
+        mean_grad = gsum / jnp.maximum(count, 1.0)
+        edges = mean_grad > cfg.edge_threshold
+    return to_host(edges)
 
 
 def _binary_dilate(x: jnp.ndarray) -> jnp.ndarray:
@@ -58,12 +66,13 @@ def _binary_erode(x: jnp.ndarray) -> jnp.ndarray:
 def clean_edges(edges: np.ndarray, closing_steps: int = 1) -> np.ndarray:
     """Stage 4: morphological closing (dilate then erode) bridges one-pixel
     gaps in field boundaries without fattening them permanently."""
-    x = jnp.asarray(edges)
-    for _ in range(closing_steps):
-        x = _binary_dilate(x)
-    for _ in range(closing_steps):
-        x = _binary_erode(x)
-    return np.asarray(x)
+    (x,) = to_device(edges)
+    with span("dispatch"):
+        for _ in range(closing_steps):
+            x = _binary_dilate(x)
+        for _ in range(closing_steps):
+            x = _binary_erode(x)
+    return to_host(x)
 
 
 @jax.jit
@@ -106,24 +115,28 @@ def polygonize(labels: np.ndarray, min_pixels: int = 8) -> Dict:
     representation keeps this dependency-free while preserving the
     downstream contract: one feature per field, georeferencable geometry).
     """
-    labels = np.asarray(labels)
-    ids, counts = np.unique(labels[labels > 0], return_counts=True)
-    feats = []
-    for lab, count in zip(ids, counts):
-        if count < min_pixels:
-            continue
-        ys, xs = np.nonzero(labels == lab)
-        y0, y1, x0, x1 = ys.min(), ys.max() + 1, xs.min(), xs.max() + 1
-        feats.append({
-            "type": "Feature",
-            "properties": {"field_id": int(lab), "pixels": int(count),
-                           "centroid": [float(xs.mean()), float(ys.mean())]},
-            "geometry": {"type": "Polygon",
-                         "coordinates": [[[int(x0), int(y0)], [int(x1), int(y0)],
-                                          [int(x1), int(y1)], [int(x0), int(y1)],
-                                          [int(x0), int(y0)]]]},
-        })
-    return {"type": "FeatureCollection", "features": feats}
+    with span("polygonize"):
+        labels = np.asarray(labels)
+        ids, counts = np.unique(labels[labels > 0], return_counts=True)
+        feats = []
+        for lab, count in zip(ids, counts):
+            if count < min_pixels:
+                continue
+            ys, xs = np.nonzero(labels == lab)
+            y0, y1, x0, x1 = ys.min(), ys.max() + 1, xs.min(), xs.max() + 1
+            feats.append({
+                "type": "Feature",
+                "properties": {"field_id": int(lab), "pixels": int(count),
+                               "centroid": [float(xs.mean()),
+                                            float(ys.mean())]},
+                "geometry": {"type": "Polygon",
+                             "coordinates": [[[int(x0), int(y0)],
+                                              [int(x1), int(y0)],
+                                              [int(x1), int(y1)],
+                                              [int(x0), int(y1)],
+                                              [int(x0), int(y0)]]]},
+            })
+        return {"type": "FeatureCollection", "features": feats}
 
 
 def segment_tile(images: np.ndarray, valid: np.ndarray,
@@ -132,7 +145,11 @@ def segment_tile(images: np.ndarray, valid: np.ndarray,
     """Full §V.B chain for one tile -> (labels [H, W], geojson dict)."""
     edges = temporal_edges(images, valid, cfg, impl=impl)
     edges = clean_edges(edges)
-    labels = np.asarray(connected_components(jnp.asarray(~edges)))
+    (fields,) = to_device(~edges)
+    with span("dispatch"):
+        labels = connected_components(fields)
+    del fields
+    labels = to_host(labels)
     return labels, polygonize(labels)
 
 
@@ -144,9 +161,10 @@ def segment_to_store(cs, tile_name: str, cfg: ImageryConfig,
     labels, geo = segment_tile(imgs, valid, cfg)
     arr = cs.create(f"{out_prefix}/{tile_name}/labels", labels.shape,
                     labels.dtype, labels.shape, codec="zlib")
-    arr.write_region((0, 0), labels)
-    cs.fs.write(f"{cs.root}/{out_prefix}/{tile_name}/fields.geojson",
-                json.dumps(geo).encode())
+    with span("write"):
+        arr.write_region((0, 0), labels)
+        cs.fs.write(f"{cs.root}/{out_prefix}/{tile_name}/fields.geojson",
+                    json.dumps(geo).encode())
     return {"tile": tile_name, "fields": len(geo["features"])}
 
 

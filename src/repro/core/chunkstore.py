@@ -36,6 +36,7 @@ import numpy as np
 
 from repro.core import codec as codec_mod
 from repro.core.festivus import Festivus
+from repro.core.spans import span
 
 MANIFEST = ".manifest"
 
@@ -237,13 +238,19 @@ class ChunkedArray:
         idx = tuple(int(i) for i in idx)
         shape = self.chunk_shape(idx, level)
         key = self._key(idx, level)
-        if not self.store.fs.exists(key):
+        with span("fetch"):
+            # read_view: the codec decodes straight out of the block cache /
+            # store buffer (raw chunks: zero copies until the final owned
+            # ndarray) — same block requests and modeled service time as
+            # read()
+            view = (self.store.fs.read_view(key)
+                    if self.store.fs.exists(key) else None)
+        if view is None:
             return np.full(shape, self.spec.fill_value, dtype=self._np_dtype)
-        # read_view: the codec decodes straight out of the block cache /
-        # store buffer (raw chunks: zero copies until the final owned
-        # ndarray) — same block requests and modeled service time as read()
-        raw = codec_mod.decode(self.store.fs.read_view(key))
-        return np.frombuffer(raw, dtype=self._np_dtype).reshape(shape).copy()
+        with span("decode"):
+            raw = codec_mod.decode(view)
+            return np.frombuffer(raw, dtype=self._np_dtype).reshape(
+                shape).copy()
 
     def chunk_exists(self, idx: Sequence[int]) -> bool:
         return self.store.fs.exists(self._key(tuple(int(i) for i in idx)))
@@ -465,11 +472,12 @@ class ChunkedArray:
         has never been built.  Both paths consume the dirty set and bump
         the array generation.
         """
-        if self.spec.pyramid_levels <= 0:
-            return 0
-        if not full and self.pyramid_built():
-            return self._build_pyramid_incremental()
-        return self._build_pyramid_full()
+        with span("pyramid"):
+            if self.spec.pyramid_levels <= 0:
+                return 0
+            if not full and self.pyramid_built():
+                return self._build_pyramid_incremental()
+            return self._build_pyramid_full()
 
     def _build_pyramid_full(self) -> int:
         dh, dw = self._spatial_dims()  # always adjacent: dw == dh + 1

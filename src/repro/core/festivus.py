@@ -409,6 +409,7 @@ class Festivus:
                         sleep=self._retry_sleep,
                         budget_s=self.config.retry_budget_s,
                         on_retry=self._count_retry)
+        self._forget_inflight(path)
         self._cache.invalidate_path(path)
         if self._ssd is not None:
             self._ssd.invalidate_path(path)
@@ -422,12 +423,23 @@ class Festivus:
                  sleep=self._retry_sleep,
                  budget_s=self.config.retry_budget_s,
                  on_retry=self._count_retry)
+        self._forget_inflight(path)
         self._cache.invalidate_path(path)
         if self._ssd is not None:
             self._ssd.invalidate_path(path)
         self.statcache.remove(path)
         for hook in self.write_hooks:
             hook(path)
+
+    def _forget_inflight(self, path: str) -> None:
+        """Stop later reads of `path` from joining a fetch issued before
+        it was rewritten.  A fetch's future wakes its reader before the
+        pool thread runs the done-callback that unregisters it, so a
+        read-modify-write that PUTs and reads the chunk again at once could
+        otherwise join its own earlier, now stale, fetch."""
+        with self._inflight_lock:
+            for key in [k for k in self._inflight if k[0] == path]:
+                del self._inflight[key]
 
     def drain_ssd_pending(self) -> float:
         """Device read-time accrued by SSD hits since the last drain.
@@ -581,7 +593,8 @@ class Festivus:
 
             def _done(f, key=key):
                 with self._inflight_lock:
-                    self._inflight.pop(key, None)
+                    if self._inflight.get(key) is f:
+                        del self._inflight[key]
 
             fut.add_done_callback(_done)
             return fut

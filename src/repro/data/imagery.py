@@ -128,6 +128,9 @@ def write_scene_stack(cs: ChunkStore, name: str, spec: SceneSpec,
 
 
 def read_scene_stack(cs: ChunkStore, name: str):
-    imgs = cs.open(f"{name}/images").read_all()
-    valid = cs.open(f"{name}/valid").read_all().astype(bool)
-    return imgs, valid
+    from repro.core.spans import span
+
+    with span("read"):
+        imgs = cs.open(f"{name}/images").read_all()
+        valid = cs.open(f"{name}/valid").read_all().astype(bool)
+        return imgs, valid

@@ -80,6 +80,7 @@ from repro.core import perfmodel
 from repro.core.chunkstore import ChunkStore
 from repro.core.festivus import Festivus, FestivusConfig, FestivusStats, SsdTier
 from repro.core.metadata import MetadataStore
+from repro.core.spans import span
 from repro.core.object_store import (ObjectStore, StoreStats,
                                      TransientStoreError)
 from repro.core.taskqueue import TaskQueue
@@ -1016,7 +1017,8 @@ class ClusterEngine:
                 t_task = time.monotonic()
                 error = result = None
                 try:
-                    result = handler(worker, task.payload)
+                    with span("task", task=task.task_id, worker=worker.name):
+                        result = handler(worker, task.payload)
                 except Exception as e:  # noqa: BLE001 — a worker never dies
                     error = f"{type(e).__name__}: {e}"
                 worker.clock.advance(time.monotonic() - t_task)

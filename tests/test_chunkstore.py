@@ -178,6 +178,25 @@ def test_two_concurrent_writers_lose_no_update():
     assert np.allclose(out[2:4], 2.0)
 
 
+def test_read_after_rewrite_never_joins_the_fetch_before_it():
+    """A finished fetch stays registered until the pool thread runs its
+    done-callback, after its reader has woken.  A rewrite of the object
+    must not let the next read join it: sequential unaligned writes to
+    one chunk read the chunk straight after PUTting it."""
+    from concurrent.futures import Future
+
+    store, meta, cs, arr, data = _world(shape=(8, 8, 2), chunks=(8, 8, 2),
+                                        levels=0)
+    key = arr._key((0, 0, 0))
+    stale = Future()
+    stale.set_result(cs.fs.read_view(key))  # the fetch, not yet unregistered
+    cs.fs._inflight[(key, 0)] = stale
+    arr.write_region((0, 0, 0), np.full((8, 8, 2), 5.0, dtype=np.float32))
+    arr.write_region((0, 0, 0), np.full((2, 8, 2), 7.0, dtype=np.float32))
+    out = arr.read_all()
+    assert np.all(out[:2] == 7.0) and np.all(out[2:] == 5.0)
+
+
 # ---------------------------------------------------------------------------
 # incremental pyramid == full rebuild (the oracle)
 # ---------------------------------------------------------------------------
