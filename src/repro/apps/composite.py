@@ -15,8 +15,11 @@ the worker-pull queue of §V.A.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.festivus_imagery import ImageryConfig
@@ -33,11 +36,16 @@ from repro.launch.cluster import (
 )
 
 
-def cloud_score(images: np.ndarray, cfg: ImageryConfig) -> np.ndarray:
+def cloud_score(images: np.ndarray | jax.Array,
+                cfg: ImageryConfig) -> np.ndarray | jax.Array:
     """Simple reflectance cloud mask ([12] Oreopoulos et al. in the paper):
     clouds are bright and spectrally flat.  images [T, H, W, C] -> [T, H, W]
-    score in [0, 1]."""
+    score in [0, 1].  A host array is scored in NumPy; a ``jax.Array`` by
+    the same formula in a device program, returned without waiting."""
     with span("band_math"):
+        if isinstance(images, jax.Array):
+            return _device_cloud_score(images,
+                                       cfg.cloud_reflectance_threshold)
         brightness = images[..., :3].mean(axis=-1)
         flatness = 1.0 - np.abs(images[..., 0] - images[..., 2])
         score = np.clip(
@@ -45,19 +53,35 @@ def cloud_score(images: np.ndarray, cfg: ImageryConfig) -> np.ndarray:
         return score * np.clip(flatness, 0.0, 1.0)
 
 
+@functools.partial(jax.jit, static_argnames=("threshold",))
+def _device_cloud_score(images: jax.Array, threshold: float) -> jax.Array:
+    """:func:`cloud_score`'s NumPy formula, in f32 on the device."""
+    brightness = images[..., :3].mean(axis=-1)
+    flatness = 1.0 - jnp.abs(images[..., 0] - images[..., 2])
+    score = jnp.clip((brightness - threshold) * 4.0, 0.0, 1.0)
+    return score * jnp.clip(flatness, 0.0, 1.0)
+
+
+@jax.jit
+def composite_weights(images: jax.Array, score: jax.Array) -> jax.Array:
+    """The paper's weights of a stack [T, H, W, C] and its cloud score, in
+    one program that slices the stack's nir and red bands itself."""
+    return kref.composite_weights(score, nir=images[..., 1],
+                                  red=images[..., 0])
+
+
 def composite_tile(images: np.ndarray, cfg: ImageryConfig,
                    impl: str = "auto") -> np.ndarray:
-    """One tile: [T, H, W, C] stack -> [H, W, C] cloud-free composite."""
-    score = cloud_score(images, cfg)
-    unread, score_d, nir, red = to_device(images, score, images[..., 1],
-                                          images[..., 0])
-    with span("dispatch"):
-        weights = kref.composite_weights(unread, score_d, nir=nir, red=red)
-    del unread, score_d, nir, red  # the device frees them after the call
+    """One tile: [T, H, W, C] stack -> [H, W, C] cloud-free composite.  The
+    stack crosses to the device once; score, weights and composite are
+    computed there."""
     (stack,) = to_device(images)
+    score = cloud_score(stack, cfg)
     with span("dispatch"):
+        weights = composite_weights(stack, score)
+        del score  # freed once the weights are done, before the kernel runs
         out = kops.composite(stack, weights, impl=impl)
-    del stack
+    del stack, weights
     return to_host(out)
 
 

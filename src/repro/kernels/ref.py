@@ -129,8 +129,7 @@ def composite(images: jax.Array, weights: jax.Array,
     return (num / (den + eps)).astype(images.dtype)
 
 
-def composite_weights(images: jax.Array, cloud_score: jax.Array,
-                      nir: jax.Array, red: jax.Array,
+def composite_weights(cloud_score: jax.Array, nir: jax.Array, red: jax.Array,
                       eps: float = 1e-6) -> jax.Array:
     """The paper's weighting: favor cloud-free, verdant pixels.
 

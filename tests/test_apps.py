@@ -152,6 +152,65 @@ def test_cloud_score_flags_bright_flat(scene_store):
     assert score[~valid].mean() > score[valid].mean()
 
 
+def smoke_stack(seed: int) -> np.ndarray:
+    """A seeded [T, H, W, C] f32 stack at the smoke tile size."""
+    spec = imagery.SceneSpec(tile_px=IMG_CFG.composite_tile_px,
+                             temporal_depth=IMG_CFG.temporal_depth, seed=seed)
+    return imagery.scene_stack(spec)[0]
+
+
+STACK_SEEDS = [0, 7, 3000001401]
+
+
+@pytest.mark.parametrize("seed", STACK_SEEDS)
+def test_cloud_score_of_a_device_array_is_the_host_formula(seed):
+    import jax
+    import jax.numpy as jnp
+
+    images = smoke_stack(seed)
+    host = composite.cloud_score(images, IMG_CFG)
+    device = composite.cloud_score(jnp.asarray(images), IMG_CFG)
+    assert isinstance(host, np.ndarray) and isinstance(device, jax.Array)
+    assert host.shape == device.shape == images.shape[:-1]
+    assert host.dtype == device.dtype == np.float32
+    np.testing.assert_allclose(np.asarray(device), host, rtol=0, atol=1e-6)
+
+
+@pytest.mark.parametrize("seed", STACK_SEEDS)
+def test_composite_tile_sends_the_stack_once(seed, monkeypatch):
+    images = smoke_stack(seed)
+    to_device = composite.to_device
+    sent = []
+
+    def recording(*arrays):
+        sent.append([a.nbytes for a in arrays])
+        return to_device(*arrays)
+
+    monkeypatch.setattr(composite, "to_device", recording)
+    for _ in range(2):
+        composite.composite_tile(images, IMG_CFG, impl="ref")
+    assert sent == [[images.nbytes]] * 2
+
+
+@pytest.mark.parametrize("impl", ["ref", "pallas"])
+@pytest.mark.parametrize("seed", STACK_SEEDS)
+def test_composite_tile_is_the_host_weights_formula(seed, impl):
+    """The paper's recipe written out on the host, in float64: cloud score,
+    NDVI verdancy, weights, weighted mean over time."""
+    images = smoke_stack(seed)
+    x = images.astype(np.float64)
+    red, nir, green = x[..., 0], x[..., 1], x[..., 2]
+    cloud = (np.clip(((red + nir + green) / 3
+                      - IMG_CFG.cloud_reflectance_threshold) * 4, 0, 1)
+             * np.clip(1 - np.abs(red - green), 0, 1))
+    ndvi = (nir - red) / (nir + red + 1e-6)
+    w = (1 - cloud) * (0.25 + 0.75 * np.clip(ndvi, 0, 1))
+    want = (w[..., None] * x).sum(0) / (w.sum(0)[..., None] + 1e-6)
+    got = composite.composite_tile(images, IMG_CFG, impl=impl)
+    assert got.shape == want.shape and got.dtype == np.float32
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-5)
+
+
 # ---------------------------------------------------------------------------
 # segmentation (§V.B)
 # ---------------------------------------------------------------------------

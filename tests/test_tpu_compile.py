@@ -81,6 +81,31 @@ def test_composite_compiles_at_paper_width(width, depth, one_chip,
     _check(_compile(composite_fwd, one_chip, *shapes), shapes)
 
 
+@pytest.mark.parametrize("program", ["cloud_score", "weights"])
+def test_composite_band_math_compiles_at_paper_width(program, one_chip,
+                                                     no_compile_cache):
+    """The composite's score and weights programs read the stack as it
+    lands on the device: no lane padding of the bands, output one f32
+    plane per scene."""
+    from repro.apps.composite import _device_cloud_score, composite_weights
+    from repro.configs.festivus_imagery import DEFAULT
+
+    depth, width = 16, 4096
+    shapes = [((depth, width, width, BANDS), jnp.float32)]
+    if program == "weights":
+        shapes.append(((depth, width, width), jnp.float32))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    if program == "cloud_score":
+        lowered = _device_cloud_score.lower(
+            *args, threshold=DEFAULT.cloud_reflectance_threshold)
+    else:
+        lowered = composite_weights.lower(*args)
+    memory = lowered.compile().memory_analysis()
+    unpadded = sum(int(np.prod(s)) * jnp.dtype(d).itemsize for s, d in shapes)
+    assert memory.argument_size_in_bytes <= 1.3 * unpadded
+    assert memory.output_size_in_bytes == 4 * depth * width * width
+
+
 @pytest.mark.parametrize("width,depth", [(4096, 4), (6144, 4)])
 def test_grad_mag_compiles_at_paper_width(width, depth, one_chip,
                                           no_compile_cache):
