@@ -9,6 +9,7 @@ segmentation).  Values mirror the paper where it states them.
 from __future__ import annotations
 
 import dataclasses
+from typing import Tuple
 
 from repro.core.festivus import FestivusConfig
 from repro.core.tiling import UTMGridSpec
@@ -33,6 +34,10 @@ class ImageryConfig:
     #: 1024 x 1024 x 4 bands x uint16 = 8 MiB/chunk before compression)
     chunk_px: int = 1024
     codec: str = "zlib"
+    #: §V.B chips per tile as (rows, cols): (1, 1) segments a tile on one
+    #: chip; (2, 2) splits it by rows and columns over a 2x2 mesh, with
+    #: halo exchange and labels merged across the shards
+    segmentation_mesh: Tuple[int, int] = (1, 1)
 
     def utm_spec(self, resolution_m: float | None = None) -> UTMGridSpec:
         return UTMGridSpec(tile_px=self.composite_tile_px, border_px=16,

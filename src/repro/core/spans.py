@@ -10,13 +10,12 @@ profile active a span costs about a microsecond and records nothing.
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 PREFIX = "repro."
 #: every span the program opens, without the prefix
 NAMES = ("task", "read", "fetch", "decode", "band_math", "h2d", "dispatch",
-         "device_wait", "write", "pyramid", "polygonize")
+         "device_wait", "cc_merge", "write", "pyramid", "polygonize")
 
 
 def span(name: str, **counts) -> jax.profiler.TraceAnnotation:
@@ -24,11 +23,14 @@ def span(name: str, **counts) -> jax.profiler.TraceAnnotation:
     return jax.profiler.TraceAnnotation(PREFIX + name, **counts)
 
 
-def to_device(*arrays: np.ndarray):
+def to_device(*arrays: np.ndarray, shardings=None):
     """Send host arrays to the device, in order, and wait until they are
-    there: one ``h2d`` span counting their bytes."""
+    there: one ``h2d`` span counting their bytes.  With ``shardings`` (one
+    per array, e.g. a ``NamedSharding`` over a mesh) each array is split
+    over the devices of its sharding."""
+    shardings = shardings or (None,) * len(arrays)
     with span("h2d", bytes=sum(int(a.nbytes) for a in arrays)):
-        out = tuple(jnp.asarray(a) for a in arrays)
+        out = tuple(jax.device_put(a, s) for a, s in zip(arrays, shardings))
         jax.block_until_ready(out)
     return out
 

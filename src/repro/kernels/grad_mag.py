@@ -15,6 +15,9 @@ sequential-T grid pattern as the composite kernel.  The shifted views cost
 one extra HBM read per input; on TPU they would be produced by the XLA
 fusion feeding the kernel.  Boundary semantics match the oracle: shifted
 validity is zero outside the frame, so edge pixels contribute no gradient.
+A shard of a split frame passes its east and south neighbours' first column
+and row instead (``east``/``south``), so that its last column and row see
+the pixels beyond the seam.
 
 Layout: the kernel streams bands-major ``(C, block_h, W)`` strips, so W sits
 on the 128 lanes (see :mod:`repro.kernels.composite` for why the public
@@ -65,13 +68,19 @@ def _grad_kernel(x_ref, xe_ref, xs_ref, v_ref, ve_ref, vs_ref,
         c_ref[...] = cs[...].astype(c_ref.dtype)
 
 
-def grad_mag_fwd(images: jax.Array, valid: jax.Array, *, block_h: int = 8,
-                 eps: float = 1e-6, interpret: bool | None = None):
+def grad_mag_fwd(images: jax.Array, valid: jax.Array, *, east=None,
+                 south=None, block_h: int = 8, eps: float = 1e-6,
+                 interpret: bool | None = None):
     """images: [T, H, W, C]; valid: [T, H, W] -> (grad_sum, count) [H, W].
 
     Matches kernels.ref.grad_mag exactly (same forward-difference, same
     both-pixels-valid gating, same sqrt(.+eps)).  ``interpret=None``
     detects the backend once (TPU -> compiled, else interpreter).
+
+    ``east`` is the column just east of the frame, as (images [T, H, C],
+    valid [T, H]), and ``south`` the row just south of it, as (images
+    [T, W, C], valid [T, W]); each defaults to an invalid (zero) edge, the
+    frame's own boundary.
     """
     interpret = resolve_interpret(interpret)
     T, H, W, C = images.shape
@@ -81,13 +90,22 @@ def grad_mag_fwd(images: jax.Array, valid: jax.Array, *, block_h: int = 8,
 
     imf = jnp.transpose(images, (0, 3, 1, 2))  # [T, C, H, W]
     vf = valid.astype(images.dtype)
-    # east neighbour (shift left along W); out-of-frame -> invalid
-    xe = jnp.concatenate([imf[..., 1:], jnp.zeros_like(imf[..., :1])], axis=3)
-    ve = jnp.concatenate([vf[:, :, 1:], jnp.zeros_like(vf[:, :, :1])], axis=2)
+    # out of the frame, the shifted pixels are invalid (zero)
+    if east is None:
+        east = (jnp.zeros((T, H, C), images.dtype), jnp.zeros((T, H), bool))
+    if south is None:
+        south = (jnp.zeros((T, W, C), images.dtype), jnp.zeros((T, W), bool))
+    # east neighbour (shift left along W)
+    xe = jnp.concatenate(
+        [imf[..., 1:], jnp.transpose(east[0], (0, 2, 1))[..., None]], axis=3)
+    ve = jnp.concatenate(
+        [vf[:, :, 1:], east[1].astype(images.dtype)[..., None]], axis=2)
     # south neighbour (shift up along H)
-    xs = jnp.concatenate([imf[:, :, 1:, :], jnp.zeros_like(imf[:, :, :1, :])],
-                         axis=2)
-    vs = jnp.concatenate([vf[:, 1:, :], jnp.zeros_like(vf[:, :1, :])], axis=1)
+    xs = jnp.concatenate(
+        [imf[:, :, 1:, :], jnp.transpose(south[0], (0, 2, 1))[:, :, None, :]],
+        axis=2)
+    vs = jnp.concatenate(
+        [vf[:, 1:, :], south[1].astype(images.dtype)[:, None, :]], axis=1)
 
     grid = (H // block_h, T)
     img_spec = pl.BlockSpec((1, C, block_h, W), lambda i, t: (t, 0, i, 0))

@@ -26,16 +26,29 @@ BANDS = 4
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
         try:
-            topo = topologies.get_topology_desc(platform="tpu",
+            return topologies.get_topology_desc(platform="tpu",
                                                 topology_name="v5e:2x2")
         except Exception as e:  # noqa: BLE001 — any failure: no compiler
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh_2x2(topo):
+    from jax.sharding import Mesh
+
+    from repro.apps.segmentation import COLS, ROWS
+
+    return Mesh(np.array(topo.devices).reshape(2, 2), (ROWS, COLS))
 
 
 @pytest.fixture(scope="module")
@@ -112,3 +125,52 @@ def test_grad_mag_compiles_at_paper_width(width, depth, one_chip,
     shapes = [((depth, width, width, BANDS), jnp.float32),
               ((depth, width, width), jnp.bool_)]
     _check(_compile(grad_mag_fwd, one_chip, *shapes), shapes)
+
+
+#: one v5e chip's HBM, as its compiler counts it (15.75 GiB)
+HBM_BYTES = 16909336064
+
+
+@pytest.mark.parametrize("program", ["split_grad_mag", "split_components"])
+def test_split_segmentation_compiles_on_a_2x2_mesh(program, mesh_2x2,
+                                                   no_compile_cache,
+                                                   monkeypatch):
+    """The §V.B tile at the paper's depth of 16, split 2x2: each chip's
+    shard lands unpadded, and each chip's plan fits its memory."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.apps import segmentation as seg
+    from repro.configs.festivus_imagery import DEFAULT
+    from repro.kernels import grad_mag
+
+    # the kernel as the chip compiles it, not the CPU's interpreter
+    monkeypatch.setattr(grad_mag, "resolve_interpret", lambda _: False)
+
+    depth, width = DEFAULT.temporal_depth, DEFAULT.segmentation_tile_px
+    rows, cols = seg.ROWS, seg.COLS
+    if program == "split_grad_mag":
+        shapes = [((depth, width, width, BANDS), jnp.float32,
+                   P(None, rows, cols, None)),
+                  ((depth, width, width), jnp.bool_, P(None, rows, cols))]
+        args = [jax.ShapeDtypeStruct(s, d,
+                                     sharding=NamedSharding(mesh_2x2, spec))
+                for s, d, spec in shapes]
+        lowered = seg.split_grad_mag.lower(
+            *args, mesh=mesh_2x2, threshold=DEFAULT.edge_threshold)
+    else:
+        shapes = [((width, width), jnp.bool_, P(rows, cols))]
+        args = [jax.ShapeDtypeStruct(s, d,
+                                     sharding=NamedSharding(mesh_2x2, spec))
+                for s, d, spec in shapes]
+        lowered = seg.split_components.lower(*args, mesh=mesh_2x2)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "collective-permute" in text
+    assert ("tpu_custom_call" in text) == (program == "split_grad_mag")
+    memory = compiled.memory_analysis()
+    shard = sum(int(np.prod(s)) * jnp.dtype(d).itemsize
+                for s, d, _ in shapes) // 4
+    assert memory.argument_size_in_bytes <= 1.3 * shard
+    planned = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+               + memory.temp_size_in_bytes - memory.alias_size_in_bytes)
+    assert planned < HBM_BYTES, planned
